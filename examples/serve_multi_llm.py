@@ -1,67 +1,50 @@
 """End-to-end driver (paper kind = serving): serve TWO small models with
 batched requests through real JAX engines behind a Coral-style
-weighted-round-robin router, and report per-model latency/throughput.
+round-robin router (``repro.launch.serve.route``, which also spreads a
+model's requests over its replicas), and report per-model latency and
+throughput.
 
 Run:  PYTHONPATH=src python examples/serve_multi_llm.py
 """
-import time
+from typing import Dict
 
-import jax
 import numpy as np
 
 from repro.configs.registry import get_smoke_config
-from repro.models import api as mapi
-from repro.obs.percentiles import percentiles
-from repro.serving.engine import JaxEngine
+from repro.launch.serve import build_engine, latency_stats, report, route
 
 ARCHS = ["qwen2-1.5b", "glm4-9b"]
 N_REQ, RATE = 16, 4.0
 
-engines = {}
-for arch in ARCHS:
-    cfg = get_smoke_config(arch)
-    model = mapi.get_model(cfg)
-    params, _ = model.init(jax.random.PRNGKey(0), cfg)
-    engines[arch] = (cfg, JaxEngine(cfg, params, max_batch=4, max_len=128))
-    print(f"[init] {arch}: {cfg.n_layers}L d={cfg.d_model} (reduced)")
 
-rng = np.random.default_rng(0)
-trace = []
-t = 0.0
-for i in range(N_REQ * len(ARCHS)):
-    t += rng.exponential(1.0 / (RATE * len(ARCHS)))
-    trace.append((t, ARCHS[i % len(ARCHS)], i))
+def make_trace(vocab: Dict[str, int], n_per_model: int, rate: float, rng):
+    """Poisson arrivals alternating over the models: a list of
+    (arrival_s, arch, rid, prompt, max_new)."""
+    archs = list(vocab)
+    trace, t = [], 0.0
+    for rid in range(n_per_model * len(archs)):
+        arch = archs[rid % len(archs)]
+        t += rng.exponential(1.0 / (rate * len(archs)))
+        prompt = rng.integers(0, vocab[arch], size=(int(rng.integers(8, 48)),))
+        trace.append((t, arch, rid, prompt, int(rng.integers(8, 24))))
+    return trace
 
-t0 = time.time()
-submitted, finished, sub_t = 0, {}, {}
-while len(finished) < len(trace):
-    now = time.time() - t0
-    while submitted < len(trace) and trace[submitted][0] <= now:
-        _, arch, rid = trace[submitted]
-        cfg, eng = engines[arch]
-        eng.submit(rid, rng.integers(0, cfg.vocab_size,
-                                     size=(int(rng.integers(8, 48)),)),
-                   int(rng.integers(8, 24)))
-        sub_t[rid] = (arch, time.time())
-        submitted += 1
-    progressed = False
-    for arch, (cfg, eng) in engines.items():
-        if any(eng.slots) or eng.queue:
-            reqs = {s.rid: s for s in eng.slots if s is not None}
-            for rid, _tok, done in eng.step():
-                if done:
-                    finished[rid] = reqs[rid]
-            progressed = True
-    if not progressed:
-        time.sleep(0.004)
 
-wall = time.time() - t0
-print(f"\nserved {len(finished)} requests across {len(ARCHS)} models "
-      f"in {wall:.1f}s")
-for arch in ARCHS:
-    rids = [r for r, (a, _) in sub_t.items() if a == arch and r in finished]
-    ttft = [finished[r].prefill_done - sub_t[r][1] for r in rids]
-    toks = sum(len(finished[r].out_tokens) for r in rids)
-    p50, p95 = percentiles(ttft, (0.50, 0.95))
-    print(f"  {arch:12s} {len(rids):3d} reqs {toks:5d} tokens "
-          f"TTFT p50={p50*1e3:.0f}ms p95={p95*1e3:.0f}ms")
+def main():
+    replicas = {}
+    for arch in ARCHS:
+        cfg = get_smoke_config(arch)
+        replicas[arch] = [build_engine(cfg, max_batch=4, max_len=128)]
+        print(f"[init] {arch}: {cfg.n_layers}L d={cfg.d_model} (reduced)")
+    vocab = {a: g[0].cfg.vocab_size for a, g in replicas.items()}
+    trace = make_trace(vocab, N_REQ, RATE, np.random.default_rng(0))
+
+    finished, sub_t, _ = route(replicas, trace)
+    print(f"\nserved {len(finished)} requests across {len(ARCHS)} models")
+    for arch in ARCHS:
+        rids = [rid for _, a, rid, _, _ in trace if a == arch]
+        report(latency_stats(finished, sub_t, rids), arch)
+
+
+if __name__ == "__main__":
+    main()

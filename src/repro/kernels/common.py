@@ -1,7 +1,6 @@
 """Shared kernel utilities: impl selection, padding helpers."""
 from __future__ import annotations
 
-import os
 from functools import lru_cache
 
 import jax
@@ -12,15 +11,13 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
 
 @lru_cache(None)
 def default_impl() -> str:
-    """'pallas' on TPU, 'ref' elsewhere (overridable via REPRO_KERNEL_IMPL).
+    """'pallas' on TPU, 'ref' elsewhere.
 
     Pallas kernels are authored for the TPU target and validated on CPU in
     interpret mode ('pallas_interpret'); XLA-fused jnp references are the
-    fast path on this CPU container.
+    fast path on CPU. Only an explicit ``impl=`` argument selects another
+    implementation, so a TPU run always takes the Pallas kernels.
     """
-    env = os.environ.get("REPRO_KERNEL_IMPL")
-    if env:
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 

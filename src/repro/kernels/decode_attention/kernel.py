@@ -26,6 +26,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG_INF
 
+# KV block streamed per grid step; the cache length must be a multiple of
+# min(BLOCK_K, cache length)
+BLOCK_K = 256
+
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *,
@@ -77,7 +81,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "window", "bk",
                                              "interpret"))
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *, scale=None,
-                            window=0, bk=256, interpret=False):
+                            window=0, bk=BLOCK_K, interpret=False):
     """q: (B, H, D); k/v_cache: (B, Smax, KH, D); lengths: (B,) -> (B, H, D)."""
     B, H, D = q.shape
     _, S, KH, _ = k_cache.shape

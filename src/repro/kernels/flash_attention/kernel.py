@@ -26,6 +26,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import NEG_INF
 
+# query/KV block edge; a sequence must be a multiple of min(BLOCK, its length)
+BLOCK = 128
+
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                       scale: float, causal: bool, window: int,
@@ -88,7 +91,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
                                              "bq", "bk", "interpret"))
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, scale=None,
-                        bq=128, bk=128, interpret=False):
+                        bq=BLOCK, bk=BLOCK, interpret=False):
     """q: (B, Sq, H, D); k/v: (B, Sk, KH, D) -> (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape

@@ -41,16 +41,19 @@ def _ssd_kernel(A_ref, D_ref, x_ref, dt_ref, B_ref, C_ref, s0_ref,
     Bm = B_ref[0].astype(jnp.float32)              # (T, N)
     Cm = C_ref[0].astype(jnp.float32)              # (T, N)
 
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    u_idx = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
     loglam = dt * a                                # (T, 1)
-    cum = jnp.cumsum(loglam, axis=0)               # (T, 1) log L_t
+    # prefix sum as a lower-triangular masked row sum: Mosaic has no
+    # cumsum lowering, and a lane reduction keeps it exact in fp32
+    cum = jnp.sum(jnp.where(u_idx <= t_idx, loglam.reshape(1, T), 0.0),
+                  axis=1, keepdims=True)           # (T, 1) log L_t
     Lt = jnp.exp(cum)                              # (T, 1)
 
     # intra-chunk score M[t,u] = (C_t.B_u) * dt_u * exp(cum_t - cum_u), u<=t
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (T, T)
     ratio = jnp.exp(cum - cum.reshape(1, T))       # (T, T) exp(cum_t - cum_u)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    u_idx = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
     M = cb * dt.reshape(1, T) * ratio
     M = jnp.where(u_idx <= t_idx, M, 0.0)
     y = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),

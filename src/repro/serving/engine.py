@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attention.kernel import BLOCK_K as DECODE_BLOCK
+from repro.kernels.flash_attention.kernel import BLOCK as PREFILL_BLOCK
 from repro.models import api as mapi
 from repro.train import steps
 
@@ -31,6 +33,21 @@ def _bucket(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def check_max_len(max_len: int) -> None:
+    """Reject a cache length the attention kernels cannot tile: decode
+    streams the cache in blocks of min(DECODE_BLOCK, max_len), and the
+    largest prefill bucket (max_len itself) is tiled in blocks of
+    min(PREFILL_BLOCK, max_len)."""
+    if max_len < 1:
+        raise ValueError(f"max_len={max_len} must be positive")
+    for kernel, block in (("decode_attention", DECODE_BLOCK),
+                          ("flash_attention", PREFILL_BLOCK)):
+        if max_len % min(block, max_len):
+            raise ValueError(
+                f"max_len={max_len} cannot be tiled by {kernel}: it needs "
+                f"max_len % min({block}, max_len) == 0")
 
 
 @dataclass
@@ -45,15 +62,22 @@ class EngineRequest:
 
 
 class JaxEngine:
+    """``device`` holds the params and the cache, and so runs every step
+    (default ``jax.devices()[0]``); one engine per chip serves replicas."""
+
     def __init__(self, cfg, params, max_batch: int = 8, max_len: int = 512,
-                 greedy: bool = True):
+                 greedy: bool = True, device=None):
+        check_max_len(max_len)
         self.cfg = cfg
-        self.params = params
+        self.device = device or jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.model = mapi.get_model(cfg)
         self.max_batch = max_batch
         self.max_len = max_len
         dt = jnp.dtype(cfg.dtype)
-        self.cache, _ = self.model.init_cache(cfg, max_batch, max_len, dt)
+        with jax.default_device(self.device):
+            cache, _ = self.model.init_cache(cfg, max_batch, max_len, dt)
+        self.cache = jax.device_put(cache, self.device)
         self._serve = jax.jit(steps.make_serve_step(cfg), donate_argnums=(1,))
         self._prefill = jax.jit(
             lambda p, b, lp: self.model.prefill(p, cfg, b, lp))
@@ -77,7 +101,13 @@ class JaxEngine:
         return jax.tree.map(upd, cache, pre_cache)
 
     def submit(self, rid: int, prompt: np.ndarray, max_new: int):
-        self.queue.append(EngineRequest(rid, np.asarray(prompt), max_new,
+        prompt = np.asarray(prompt)
+        # the cache holds the prompt and every decoded token but the last
+        if len(prompt) < 1 or len(prompt) + max_new > self.max_len:
+            raise ValueError(
+                f"request {rid}: a prompt of {len(prompt)} tokens plus "
+                f"{max_new} new tokens does not fit max_len={self.max_len}")
+        self.queue.append(EngineRequest(rid, prompt, max_new,
                                         submitted=time.time()))
 
     def _admit(self):
@@ -91,15 +121,16 @@ class JaxEngine:
                 bucket = S if self.cfg.is_recurrent \
                     else min(_bucket(S), self.max_len)
                 toks = np.zeros((1, bucket), np.int32)
-                toks[0, :S] = req.prompt[:bucket]
+                toks[0, :S] = req.prompt
                 t0 = time.time()
-                batch = {"tokens": jnp.asarray(toks)}
+                # host arrays go straight to the engine's device under jit
                 logits, pre_cache = self._prefill(
-                    self.params, batch, jnp.full((1,), S - 1, jnp.int32))
+                    self.params, {"tokens": toks},
+                    np.full((1,), S - 1, np.int32))
                 first = int(jnp.argmax(logits[0, :self.cfg.vocab_size])) \
                     if self.greedy else 0
                 self.cache = self._insert(self.cache, pre_cache,
-                                          jnp.int32(i), jnp.int32(S))
+                                          np.int32(i), np.int32(S))
                 jax.block_until_ready(self.cache["len"])
                 req.prefill_done = time.time()
                 req.out_tokens.append(first)
@@ -119,8 +150,7 @@ class JaxEngine:
         for i in active:
             toks[i] = self.slots[i].out_tokens[-1]
         t0 = time.time()
-        logits, self.cache = self._serve(self.params, self.cache,
-                                         jnp.asarray(toks))
+        logits, self.cache = self._serve(self.params, self.cache, toks)
         nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size], -1))
         jax.block_until_ready(nxt)
         dt = time.time() - t0

@@ -1,7 +1,16 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests must see the
-real single CPU device; only launch/dryrun.py forces 512 host devices."""
+real single CPU device; only launch/dryrun.py forces 512 host devices.
+The tests run on the CPU backend even where a TPU is attached."""
+import os
+import tempfile
+
 import numpy as np
 import pytest
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# the TPU library, where installed, logs to /tmp unless given a directory
+os.environ.setdefault("TPU_LOG_DIR",
+                      os.path.join(tempfile.gettempdir(), "tpu_logs"))
 
 
 @pytest.fixture(scope="session")

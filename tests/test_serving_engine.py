@@ -61,3 +61,24 @@ def test_engine_slot_reuse(setup):
     assert set(finished) == set(range(5))
     for r in finished.values():
         assert len(r.out_tokens) == 4       # first + 3 generated
+
+
+@pytest.mark.parametrize("max_len,kernel", [(200, "flash_attention"),
+                                            (384, "decode_attention")])
+def test_engine_rejects_untileable_max_len(setup, max_len, kernel):
+    """200 leaves a partial 128-row prefill block at the max_len bucket;
+    384 leaves a partial 256-row decode block."""
+    cfg, _, params = setup
+    with pytest.raises(ValueError, match=f"cannot be tiled by {kernel}"):
+        JaxEngine(cfg, params, max_batch=2, max_len=max_len)
+
+
+@pytest.mark.parametrize("n_prompt,max_new", [(65, 1), (60, 5), (0, 3)])
+def test_submit_rejects_request_beyond_max_len(setup, n_prompt, max_new):
+    cfg, _, params = setup
+    eng = JaxEngine(cfg, params, max_batch=2, max_len=64)
+    with pytest.raises(ValueError, match="does not fit max_len=64"):
+        eng.submit(0, np.zeros((n_prompt,), np.int32), max_new)
+    assert not eng.queue
+    eng.submit(1, np.zeros((60,), np.int32), 4)     # exactly fills the cache
+    assert len(eng.drain()[1].out_tokens) == 5
