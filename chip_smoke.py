@@ -183,11 +183,12 @@ def serving_phase(cfg, seed: int, max_len: int = MAX_LEN,
     trace = [(t, cfg.name, rid, rng.integers(0, cfg.vocab_size, (n,)), int(m))
              for rid, (t, n, m) in enumerate(zip(arrivals, lens, max_news))]
     warm_up(eng, lens)
-    finished, sub_t, _ = launch.route({cfg.name: [eng]}, trace)
+    finished, arrived, _ = launch.route({cfg.name: [eng]}, trace)
     check_outputs(finished, range(N_REQUESTS), cfg.vocab_size)
     print(f"[serve] prompt lengths {sorted(lens)}; every request finished "
           f"with max_new+1 tokens below vocab_size", flush=True)
-    launch.report(launch.latency_stats(finished, sub_t, range(N_REQUESTS)),
+    launch.report(launch.latency_stats(finished, arrived,
+                                       range(N_REQUESTS)),
                   "serve, bring-up reading, not a benchmark")
     for rid, n in enumerate(exact):
         want = direct_greedy(cfg, eng.params, trace[rid][3], trace[rid][4],
@@ -226,7 +227,7 @@ def replicas_phase(devices, seed: int, max_len: int = 256):
     for group in replicas.values():
         for eng in group:
             warm_up(eng, [len(p) for _, _, _, p, _ in trace])
-    finished, sub_t, served_by = launch.route(replicas, trace)
+    finished, arrived, served_by = launch.route(replicas, trace)
     for arch, cfg in cfgs.items():
         rids = [rid for _, a, rid, _, _ in trace if a == arch]
         check_outputs(finished, rids, cfg.vocab_size)
@@ -236,7 +237,7 @@ def replicas_phase(devices, seed: int, max_len: int = 256):
                 arch, a, b, finished[a].out_tokens, finished[b].out_tokens)
         print(f"[replicas] {arch}: {len(rids) // 2} prompt pairs, the two "
               f"replicas agree token for token", flush=True)
-        launch.report(launch.latency_stats(finished, sub_t, rids),
+        launch.report(launch.latency_stats(finished, arrived, rids),
                       f"{arch}, bring-up reading, not a benchmark")
 
 

@@ -39,11 +39,11 @@ def main():
     vocab = {a: g[0].cfg.vocab_size for a, g in replicas.items()}
     trace = make_trace(vocab, N_REQ, RATE, np.random.default_rng(0))
 
-    finished, sub_t, _ = route(replicas, trace)
+    finished, arrived, _ = route(replicas, trace)
     print(f"\nserved {len(finished)} requests across {len(ARCHS)} models")
     for arch in ARCHS:
         rids = [rid for _, a, rid, _, _ in trace if a == arch]
-        report(latency_stats(finished, sub_t, rids), arch)
+        report(latency_stats(finished, arrived, rids), arch)
 
 
 if __name__ == "__main__":

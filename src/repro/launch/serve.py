@@ -64,12 +64,13 @@ def route(replicas: Dict[str, List[JaxEngine]], trace):
     (arrival_s, model, rid, prompt, max_new) in arrival order, send each
     request to the next of its model's replicas (one engine each, e.g.
     one per chip) and step every engine until all requests finish.
-    Returns (finished requests by rid, submit time by rid, replica index
-    by rid)."""
+    Returns (finished requests by rid, arrival host time by rid, replica
+    index by rid)."""
     turn = {model: 0 for model in replicas}
     engines = [e for group in replicas.values() for e in group]
     t0 = time.time()
-    submitted, finished, sub_t, served_by = 0, {}, {}, {}
+    arrived = {rid: t0 + t for t, _, rid, _, _ in trace}
+    submitted, finished, served_by = 0, {}, {}
     while len(finished) < len(trace):
         now = time.time() - t0
         while submitted < len(trace) and trace[submitted][0] <= now:
@@ -77,30 +78,36 @@ def route(replicas: Dict[str, List[JaxEngine]], trace):
             i = turn[model] % len(replicas[model])
             turn[model] += 1
             replicas[model][i].submit(rid, prompt, max_new)
-            sub_t[rid], served_by[rid] = time.time(), i
+            served_by[rid] = i
             submitted += 1
         progressed = False
         for eng in engines:
             if any(eng.slots) or eng.queue:
-                reqs = {s.rid: s for s in eng.slots if s is not None}
+                # a request admitted in this step may also finish in it
+                reqs = eng.in_flight()
                 for rid, _tok, done in eng.step():
                     if done:
                         finished[rid] = reqs[rid]
                 progressed = True
         if not progressed:
             time.sleep(0.004)
-    return finished, sub_t, served_by
+    return finished, arrived, served_by
 
 
-def latency_stats(finished, sub_t, rids) -> dict:
-    """Wall seconds from the first submit to the last token, tokens, and
-    the TTFT and token-gap samples (seconds) of requests ``rids``."""
+def latency_stats(finished, arrived, rids) -> dict:
+    """Samples (seconds) of requests ``rids`` from the engine's stamps:
+    TTFT from each request's arrival (``arrived``, host time by rid), so
+    the loop's lateness and a blocking prefill's queueing count; the
+    wait in the engine's queue (admitted less submitted); the gaps
+    between tokens. Also wall seconds from the first arrival to the last
+    token, and tokens."""
     reqs = [finished[r] for r in rids]
     end = max(r.token_times[-1] if r.token_times else r.prefill_done
               for r in reqs)
-    return {"wall_s": end - min(sub_t[r] for r in rids),
+    return {"wall_s": end - min(arrived[r] for r in rids),
             "tokens": sum(len(r.out_tokens) for r in reqs),
-            "ttft_s": [finished[r].prefill_done - sub_t[r] for r in rids],
+            "ttft_s": [finished[r].prefill_done - arrived[r] for r in rids],
+            "queue_s": [r.admitted - r.submitted for r in reqs],
             "tpot_s": [float(g) for r in reqs for g in np.diff(r.token_times)]}
 
 
@@ -112,6 +119,8 @@ def report(stats, tag: str = "serve"):
     # simulator's SLOReport uses, so engine and sim numbers line up
     f50, f95 = percentiles(stats["ttft_s"], (0.50, 0.95))
     print(f"[{tag}] TTFT   p50={f50*1e3:.1f}ms p95={f95*1e3:.1f}ms")
+    q50, q95 = percentiles(stats["queue_s"], (0.50, 0.95))
+    print(f"[{tag}] queue  p50={q50*1e3:.1f}ms p95={q95*1e3:.1f}ms")
     if stats["tpot_s"]:
         t50, t95 = percentiles(stats["tpot_s"], (0.50, 0.95))
         print(f"[{tag}] TPOT   p50={t50*1e3:.1f}ms p95={t95*1e3:.1f}ms")
@@ -126,8 +135,8 @@ def serve(cfg, n_requests: int = 32, rate: float = 5.0, max_batch: int = 8,
     arrivals = np.cumsum(rng.exponential(1.0 / rate, size=n_requests))
     trace = [(t, cfg.name, rid, rng.integers(0, cfg.vocab_size, size=(int(n),)),
               int(m)) for rid, (t, n, m) in enumerate(zip(arrivals, lens, outs))]
-    finished, sub_t, _ = route({cfg.name: [eng]}, trace)
-    report(latency_stats(finished, sub_t, sorted(finished)))
+    finished, arrived, _ = route({cfg.name: [eng]}, trace)
+    report(latency_stats(finished, arrived, sorted(finished)))
     return finished
 
 

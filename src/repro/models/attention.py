@@ -84,14 +84,17 @@ def attn_forward(p, cfg, x, positions=None, mrope_pos=None, causal=True,
 def attn_prefill(p, cfg, x, positions=None, mrope_pos=None):
     """Forward + return (out, (k_cache_slice, v_cache_slice))."""
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, cfg, x)
-    if positions is None:
-        positions = jnp.arange(S)[None, :]
-    q, k = _rope_qk(cfg, q, k, positions, mrope_pos)
-    out = fa_ops.flash_attention(q, k, v, causal=True,
-                                 window=cfg.sliding_window)
-    out = out.reshape(B, S, -1)
-    return out @ p["wo"], (k, v)
+    with jax.named_scope("attn_qkv"):
+        q, k, v = _project_qkv(p, cfg, x)
+        if positions is None:
+            positions = jnp.arange(S)[None, :]
+        q, k = _rope_qk(cfg, q, k, positions, mrope_pos)
+    with jax.named_scope("attention"):
+        out = fa_ops.flash_attention(q, k, v, causal=True,
+                                     window=cfg.sliding_window)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, S, -1)
+        return out @ p["wo"], (k, v)
 
 
 def attn_decode(p, cfg, x, cache_k, cache_v, lengths, mrope_pos=None):
@@ -99,19 +102,22 @@ def attn_decode(p, cfg, x, cache_k, cache_v, lengths, mrope_pos=None):
     = #valid tokens BEFORE this one. Returns (out (B,d), new_k, new_v)."""
     B, d = x.shape
     dp = batch_axes()
-    q, k, v = _project_qkv(p, cfg, x[:, None, :])
-    pos = lengths[:, None]                       # (B,1) current position
-    if cfg.rope == "mrope":
-        q, k = _rope_qk(cfg, q, k, None, mrope_pos)
-    else:
-        q, k = _rope_qk(cfg, q, k, pos)
-    # write K/V at position `lengths`
-    idx = lengths[:, None, None, None]
-    S = cache_k.shape[1]
-    onehot = (jnp.arange(S)[None, :, None, None] == idx)
-    cache_k = jnp.where(onehot, k, cache_k)
-    cache_v = jnp.where(onehot, v, cache_v)
-    out = da_ops.decode_attention(q[:, 0], cache_k, cache_v, lengths + 1,
-                                  window=cfg.sliding_window)
-    out = constrain(out, dp, "model", None)
-    return out.reshape(B, -1) @ p["wo"], cache_k, cache_v
+    with jax.named_scope("attn_qkv"):
+        q, k, v = _project_qkv(p, cfg, x[:, None, :])
+        pos = lengths[:, None]                   # (B,1) current position
+        if cfg.rope == "mrope":
+            q, k = _rope_qk(cfg, q, k, None, mrope_pos)
+        else:
+            q, k = _rope_qk(cfg, q, k, pos)
+    with jax.named_scope("kv_write"):            # K/V at position `lengths`
+        idx = lengths[:, None, None, None]
+        S = cache_k.shape[1]
+        onehot = (jnp.arange(S)[None, :, None, None] == idx)
+        cache_k = jnp.where(onehot, k, cache_k)
+        cache_v = jnp.where(onehot, v, cache_v)
+    with jax.named_scope("attention"):
+        out = da_ops.decode_attention(q[:, 0], cache_k, cache_v, lengths + 1,
+                                      window=cfg.sliding_window)
+    with jax.named_scope("attn_out"):
+        out = constrain(out, dp, "model", None)
+        return out.reshape(B, -1) @ p["wo"], cache_k, cache_v

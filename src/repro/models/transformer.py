@@ -46,30 +46,41 @@ def layer_forward(p, cfg, h, positions, mrope_pos=None):
     return h + y, aux
 
 
+# The serving programs name their parts with ``jax.named_scope`` (in the
+# op_name metadata of every HLO op; no op changes): ``embed``, ``layers``
+# (the scan) and ``unembed``; inside a layer ``attn_qkv`` (norm,
+# projections, rope), ``kv_write`` (decode's cache write), ``attention``
+# (the kernel call and its wrapper), ``attn_out`` and ``mlp``.
 def layer_prefill(p, cfg, h, positions, mrope_pos=None):
-    xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("attn_qkv"):
+        xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
     a, kv = attn.attn_prefill(p["attn"], cfg, xn, positions, mrope_pos)
-    h = h + a
-    x = cm.rmsnorm(h, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        y, _ = mlp_mod.moe_forward(p["moe"], cfg, x)
-    else:
-        y = mlp_mod.mlp_forward(p["mlp"], cfg, x)
-    return h + y, kv
+    with jax.named_scope("attn_out"):
+        h = h + a
+    with jax.named_scope("mlp"):
+        x = cm.rmsnorm(h, p["ln2"], cfg.norm_eps)
+        if cfg.family == "moe":
+            y, _ = mlp_mod.moe_forward(p["moe"], cfg, x)
+        else:
+            y = mlp_mod.mlp_forward(p["mlp"], cfg, x)
+        return h + y, kv
 
 
 def layer_decode(p, cfg, h, ck, cv, lengths, mrope_pos=None):
-    xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("attn_qkv"):
+        xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
     a, ck, cv = attn.attn_decode(p["attn"], cfg, xn, ck, cv, lengths,
                                  mrope_pos)
-    h = h + a
-    x = cm.rmsnorm(h, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        y, _ = mlp_mod.moe_forward(p["moe"], cfg, x[:, None, :])
-        y = y[:, 0, :]
-    else:
-        y = mlp_mod.mlp_forward(p["mlp"], cfg, x)
-    return h + y, ck, cv
+    with jax.named_scope("attn_out"):
+        h = h + a
+    with jax.named_scope("mlp"):
+        x = cm.rmsnorm(h, p["ln2"], cfg.norm_eps)
+        if cfg.family == "moe":
+            y, _ = mlp_mod.moe_forward(p["moe"], cfg, x[:, None, :])
+            y = y[:, 0, :]
+        else:
+            y = mlp_mod.mlp_forward(p["mlp"], cfg, x)
+        return h + y, ck, cv
 
 
 # ------------------------------------------------------------------- model
@@ -152,18 +163,21 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
     (B, Vp), cache). ``last_pos`` (B,) overrides the sampled position for
     bucket-padded prompts (pads are never attended: cache len is set by
     the engine)."""
-    h, positions, mrope_pos = _positions_and_embeds(params, cfg, batch)
+    with jax.named_scope("embed"):
+        h, positions, mrope_pos = _positions_and_embeds(params, cfg, batch)
 
     def body(h, lp):
         h2, kv = layer_prefill(lp, cfg, h, positions, mrope_pos)
         return constrain(h2, batch_axes(), None, None), kv
 
-    h, (ks, vs) = jax.lax.scan(body, h, params["layers"])
-    hl = h[:, -1] if last_pos is None else \
-        jnp.take_along_axis(h, last_pos[:, None, None].astype(jnp.int32)
-                            .repeat(h.shape[-1], -1), axis=1)[:, 0]
-    hl = cm.rmsnorm(hl, params["ln_f"], cfg.norm_eps)
-    logits = cm.unembed(params["emb"], cfg, hl)
+    with jax.named_scope("layers"):
+        h, (ks, vs) = jax.lax.scan(body, h, params["layers"])
+    with jax.named_scope("unembed"):
+        hl = h[:, -1] if last_pos is None else \
+            jnp.take_along_axis(h, last_pos[:, None, None].astype(jnp.int32)
+                                .repeat(h.shape[-1], -1), axis=1)[:, 0]
+        hl = cm.rmsnorm(hl, params["ln_f"], cfg.norm_eps)
+        logits = cm.unembed(params["emb"], cfg, hl)
     S_tot = ks.shape[2]
     cache = {"k": ks, "v": vs,
              "len": jnp.full((h.shape[0],), S_tot, jnp.int32)}
@@ -173,7 +187,8 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
 def decode_step(params, cfg, cache, tokens):
     """One token for every sequence. tokens (B,) -> (logits (B,Vp), cache)."""
     B = tokens.shape[0]
-    h = cm.embed_tokens(params["emb"], tokens)              # (B, d)
+    with jax.named_scope("embed"):
+        h = cm.embed_tokens(params["emb"], tokens)          # (B, d)
     lengths = cache["len"]
     mrope_pos = None
     if cfg.family == "vlm":
@@ -185,9 +200,11 @@ def decode_step(params, cfg, cache, tokens):
         h2, ck, cv = layer_decode(lp, cfg, h, ck, cv, lengths, mrope_pos)
         return h2, (ck, cv)
 
-    h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], cache["k"],
-                                         cache["v"]))
-    h = cm.rmsnorm(h, params["ln_f"], cfg.norm_eps)
-    logits = cm.unembed(params["emb"], cfg, h)
+    with jax.named_scope("layers"):
+        h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], cache["k"],
+                                             cache["v"]))
+    with jax.named_scope("unembed"):
+        h = cm.rmsnorm(h, params["ln_f"], cfg.norm_eps)
+        logits = cm.unembed(params["emb"], cfg, h)
     new_cache = {"k": ks, "v": vs, "len": lengths + 1}
     return logits, new_cache

@@ -11,6 +11,18 @@ cache. Prefill runs per-request (bucketed padding), its cache is
 inserted into a free slot, and one ``serve_step`` advances every active
 slot by a token (inactive slots compute garbage that is masked out —
 the standard static-shape TPU serving pattern).
+
+Every step records its host phases as spans (``repro.obs.SpanLog``, kept
+in ``engine.spans`` and, while the profiler runs, on the device trace's
+clock): ``engine.step`` around the whole step, ``engine.admit`` around
+the admission loop and, per admitted request, ``engine.prefill`` (its
+tokens and the prefill dispatch), ``engine.first_token`` (the argmax that
+waits for the prefill) and ``engine.insert`` (the insert and its sync);
+then ``engine.decode`` (the token vector and the decode dispatch),
+``engine.sample`` (the argmax copied to the host, which waits for the
+step) and ``engine.emit`` (the per-slot loop). A request's stamps
+(``admitted``, ``prefill_done``, ``token_times``) and ``iteration_log``
+are read from the same clock reads.
 """
 from __future__ import annotations
 
@@ -25,6 +37,7 @@ import numpy as np
 from repro.kernels.decode_attention.kernel import BLOCK_K as DECODE_BLOCK
 from repro.kernels.flash_attention.kernel import BLOCK as PREFILL_BLOCK
 from repro.models import api as mapi
+from repro.obs.spans import SpanLog
 from repro.train import steps
 
 
@@ -50,14 +63,29 @@ def check_max_len(max_len: int) -> None:
                 f"max_len % min({block}, max_len) == 0")
 
 
+def engine_insert(cache, pre_cache, slot, length):
+    """Writes one prefilled request's cache into batch slot ``slot``."""
+    def upd(c, p):
+        if c.ndim == 1:                     # per-slot lengths
+            return c.at[slot].set(length)
+        # batch axis is 1; zero-pad trailing dims (kv seq) up to cache
+        pads = [(0, 0), (0, 0)]
+        for i in range(2, c.ndim):
+            pads.append((0, c.shape[i] - p.shape[i]))
+        p = jnp.pad(p, pads).astype(c.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(c, p, slot, axis=1)
+    return jax.tree.map(upd, cache, pre_cache)
+
+
 @dataclass
 class EngineRequest:
     rid: int
     prompt: np.ndarray
     max_new: int
     out_tokens: List[int] = field(default_factory=list)
-    submitted: float = 0.0
-    prefill_done: float = 0.0
+    submitted: float = 0.0          # host clock (s): when submitted
+    admitted: float = 0.0           # taken off the queue to prefill
+    prefill_done: float = 0.0       # first token on the host, cache in
     token_times: List[float] = field(default_factory=list)
 
 
@@ -79,26 +107,19 @@ class JaxEngine:
             cache, _ = self.model.init_cache(cfg, max_batch, max_len, dt)
         self.cache = jax.device_put(cache, self.device)
         self._serve = jax.jit(steps.make_serve_step(cfg), donate_argnums=(1,))
-        self._prefill = jax.jit(
-            lambda p, b, lp: self.model.prefill(p, cfg, b, lp))
-        self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
+        model = self.model
+
+        def engine_prefill(p, b, lp):
+            return model.prefill(p, cfg, b, lp)
+
+        self._prefill = jax.jit(engine_prefill)
+        self._insert = jax.jit(engine_insert, donate_argnums=(0,))
         self.slots: List[Optional[EngineRequest]] = [None] * max_batch
         self.queue: List[EngineRequest] = []
         self.greedy = greedy
         self.iteration_log: List[Tuple[str, int, float]] = []
-
-    # ------------------------------------------------------------ plumbing
-    def _insert_impl(self, cache, pre_cache, slot, length):
-        def upd(c, p):
-            if c.ndim == 1:                     # per-slot lengths
-                return c.at[slot].set(length)
-            # batch axis is 1; zero-pad trailing dims (kv seq) up to cache
-            pads = [(0, 0), (0, 0)]
-            for i in range(2, c.ndim):
-                pads.append((0, c.shape[i] - p.shape[i]))
-            p = jnp.pad(p, pads).astype(c.dtype)
-            return jax.lax.dynamic_update_slice_in_dim(c, p, slot, axis=1)
-        return jax.tree.map(upd, cache, pre_cache)
+        self.spans = SpanLog()
+        self.n_steps = 0
 
     def submit(self, rid: int, prompt: np.ndarray, max_new: int):
         prompt = np.asarray(prompt)
@@ -111,6 +132,7 @@ class JaxEngine:
                                         submitted=time.time()))
 
     def _admit(self):
+        span = self.spans.span
         for i in range(self.max_batch):
             if self.slots[i] is None and self.queue:
                 req = self.queue.pop(0)
@@ -120,58 +142,79 @@ class JaxEngine:
                 # families bucket-pad (pads masked via cache len = S).
                 bucket = S if self.cfg.is_recurrent \
                     else min(_bucket(S), self.max_len)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :S] = req.prompt
-                t0 = time.time()
-                # host arrays go straight to the engine's device under jit
-                logits, pre_cache = self._prefill(
-                    self.params, {"tokens": toks},
-                    np.full((1,), S - 1, np.int32))
-                first = int(jnp.argmax(logits[0, :self.cfg.vocab_size])) \
-                    if self.greedy else 0
-                self.cache = self._insert(self.cache, pre_cache,
-                                          np.int32(i), np.int32(S))
-                jax.block_until_ready(self.cache["len"])
-                req.prefill_done = time.time()
+                with span("engine.prefill", req.rid, bucket=bucket,
+                          prompt_len=S) as pre:
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :S] = req.prompt
+                    # host arrays go straight to the engine's device under jit
+                    logits, pre_cache = self._prefill(
+                        self.params, {"tokens": toks},
+                        np.full((1,), S - 1, np.int32))
+                with span("engine.first_token", req.rid):
+                    first = int(jnp.argmax(logits[0, :self.cfg.vocab_size])) \
+                        if self.greedy else 0
+                with span("engine.insert", req.rid) as ins:
+                    self.cache = self._insert(self.cache, pre_cache,
+                                              np.int32(i), np.int32(S))
+                    jax.block_until_ready(self.cache["len"])
+                req.admitted = pre.t0_ns * 1e-9
+                req.prefill_done = ins.t1_ns * 1e-9
                 req.out_tokens.append(first)
                 self.iteration_log.append(("prefill", bucket,
-                                           req.prefill_done - t0))
+                                           (ins.t1_ns - pre.t0_ns) * 1e-9))
                 self.slots[i] = req
 
     # ---------------------------------------------------------------- step
     def step(self) -> List[Tuple[int, int, bool]]:
         """Admit + advance every active slot one token.
         Returns [(rid, token, done)]."""
-        self._admit()
+        self.n_steps += 1
+        active = sum(s is not None for s in self.slots)
+        with self.spans.span("engine.step", step=self.n_steps, active=active,
+                             queue=len(self.queue)):
+            return self._step()
+
+    def _step(self) -> List[Tuple[int, int, bool]]:
+        span = self.spans.span
+        with span("engine.admit"):
+            self._admit()
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return []
-        toks = np.zeros((self.max_batch,), np.int32)
-        for i in active:
-            toks[i] = self.slots[i].out_tokens[-1]
-        t0 = time.time()
-        logits, self.cache = self._serve(self.params, self.cache, toks)
-        nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size], -1))
-        jax.block_until_ready(nxt)
-        dt = time.time() - t0
-        self.iteration_log.append(("decode", len(active), dt))
+        with span("engine.decode") as dec:
+            toks = np.zeros((self.max_batch,), np.int32)
+            for i in active:
+                toks[i] = self.slots[i].out_tokens[-1]
+            logits, self.cache = self._serve(self.params, self.cache, toks)
+        with span("engine.sample") as smp:
+            nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab_size], -1))
+        self.iteration_log.append(("decode", len(active),
+                                   (smp.t1_ns - dec.t0_ns) * 1e-9))
+        now = smp.t1_ns * 1e-9
         out = []
-        now = time.time()
-        for i in active:
-            req = self.slots[i]
-            req.out_tokens.append(int(nxt[i]))
-            req.token_times.append(now)
-            done = len(req.out_tokens) - 1 >= req.max_new
-            out.append((req.rid, int(nxt[i]), done))
-            if done:
-                self.slots[i] = None
+        with span("engine.emit"):
+            for i in active:
+                req = self.slots[i]
+                req.out_tokens.append(int(nxt[i]))
+                req.token_times.append(now)
+                done = len(req.out_tokens) - 1 >= req.max_new
+                out.append((req.rid, int(nxt[i]), done))
+                if done:
+                    self.slots[i] = None
         return out
+
+    def in_flight(self) -> Dict[int, EngineRequest]:
+        """Every request queued or in a slot, by rid."""
+        reqs = {r.rid: r for r in self.queue}
+        reqs.update((s.rid, s) for s in self.slots if s is not None)
+        return reqs
 
     def drain(self) -> Dict[int, EngineRequest]:
         """Run to completion; returns finished requests by rid."""
         finished: Dict[int, EngineRequest] = {}
         while any(s is not None for s in self.slots) or self.queue:
-            reqs = {s.rid: s for s in self.slots if s is not None}
+            # a request admitted in this step may also finish in it
+            reqs = self.in_flight()
             for rid, _tok, done in self.step():
                 if done:
                     finished[rid] = reqs[rid]
