@@ -101,12 +101,14 @@ def kernel_parity(impl: str = "pallas", buckets=(16, 128, MAX_LEN),
         compare(f"flash_attention S={S}",
                 fa_ops.flash_attention(q, k, v, impl=impl),
                 fa_ops.flash_attention(q, k, v, impl="ref"))
+    # the model's layer-stacked cache (L, B, S, KH*D), read at its last layer
+    L = 2
     q = normal(batch, H, D)
-    kc, vc = normal(batch, max_len, KH, D), normal(batch, max_len, KH, D)
+    kc, vc = (normal(L, batch, max_len, KH * D) for _ in range(2))
     lens = jnp.asarray(rng.integers(1, max_len + 1, size=batch), jnp.int32)
-    compare(f"decode_attention B={batch} S={max_len}",
-            da_ops.decode_attention(q, kc, vc, lens, impl=impl),
-            da_ops.decode_attention(q, kc, vc, lens, impl="ref"))
+    compare(f"decode_attention L={L} B={batch} S={max_len}",
+            da_ops.decode_attention(q, kc, vc, lens, L - 1, impl=impl),
+            da_ops.decode_attention(q, kc, vc, lens, L - 1, impl="ref"))
 
 
 def decode_step_hlo(eng) -> str:
@@ -154,7 +156,7 @@ def direct_greedy(cfg, params, prompt, n_new: int, max_len: int,
     sums differently, and random weights leave near-tied logits that
     then flip (seen on a v5e at the 18th token of a 16-token prompt)."""
     logits, cache = _prefill(params, cfg, np.asarray(prompt, np.int32)[None])
-    pad = ((0, 0), (0, 0), (0, max_len - len(prompt)), (0, 0), (0, 0))
+    pad = ((0, 0), (0, 0), (0, max_len - len(prompt)), (0, 0))
     cache = {"k": jnp.repeat(jnp.pad(cache["k"], pad), batch, axis=1),
              "v": jnp.repeat(jnp.pad(cache["v"], pad), batch, axis=1),
              "len": jnp.repeat(cache["len"], batch)}

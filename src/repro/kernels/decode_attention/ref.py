@@ -1,77 +1,42 @@
-"""Pure-jnp oracle for single-token GQA decode attention over a KV cache.
-
-Two variants: the repeat-based oracle, and a grouped-einsum form that —
-like the Pallas kernel's index_map — never materializes the H/KH-fold
-replicated KV (REPRO_GQA_GROUPED=1, the §Perf "kernel-faithful lowering"
-iteration; see EXPERIMENTS.md).
-"""
+"""Pure-jnp oracle for single-token GQA decode attention over a
+layer-stacked KV cache, in fp32."""
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import NEG_INF
 
 
-def _grouped() -> bool:
-    return os.environ.get("REPRO_GQA_GROUPED", "0") == "1"
-
-
-def decode_attention_reference(q, k_cache, v_cache, lengths, *,
+def decode_attention_reference(q, k_cache, v_cache, lengths, layer=0, *,
                                scale: float | None = None, window: int = 0):
-    """q: (B, H, D); k/v_cache: (B, Smax, KH, D); lengths: (B,) int32.
+    """q: (B, H, D); k/v_cache: (L, B, Smax, KH*D); lengths: (B,) int32;
+    layer: which of the L caches to read.
 
     Position of the query token is lengths-1 (the cache already contains
-    the current token's K/V at index lengths-1). Returns (B, H, D).
+    the current token's K/V at index lengths-1); a sequence of length 0
+    attends to nothing and gives zeros. Returns (B, H, D).
     """
-    if _grouped():
-        return decode_attention_grouped(q, k_cache, v_cache, lengths,
-                                        scale=scale, window=window)
     B, H, D = q.shape
-    _, S, KH, _ = k_cache.shape
+    _, _, S, F = k_cache.shape
+    KH = F // D
     g = H // KH
     scale = scale if scale is not None else D ** -0.5
 
-    qf = q.astype(jnp.float32) * scale
-    kf = jnp.repeat(k_cache.astype(jnp.float32), g, axis=2)  # (B,S,H,D)
-    vf = jnp.repeat(v_cache.astype(jnp.float32), g, axis=2)
+    def one_layer(c):
+        c = jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+        return c.reshape(B, S, KH, D).astype(jnp.float32)
 
-    logits = jnp.einsum("bhd,bshd->bhs", qf, kf)
-    k_pos = jnp.arange(S)[None, None, :]
-    mask = k_pos < lengths[:, None, None]
-    if window and window > 0:
-        mask &= k_pos > (lengths[:, None, None] - 1 - window)
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
-    probs = probs / (probs.sum(axis=-1, keepdims=True) + 1e-30)
-    out = jnp.einsum("bhs,bshd->bhd", probs, vf)
-    return out.astype(q.dtype)
-
-
-def decode_attention_grouped(q, k_cache, v_cache, lengths, *,
-                             scale: float | None = None, window: int = 0):
-    """GQA via grouped einsum: KV streamed once (no H/KH replication),
-    in the cache's native dtype with fp32 accumulation — a full fp32 KV
-    copy is exactly what the Pallas kernel avoids (it converts per-block
-    in VMEM)."""
-    B, H, D = q.shape
-    _, S, KH, _ = k_cache.shape
-    g = H // KH
-    scale = scale if scale is not None else D ** -0.5
-
-    qg = (q.astype(jnp.float32) * scale).astype(k_cache.dtype)
-    qg = qg.reshape(B, KH, g, D)
-
-    logits = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
-                        preferred_element_type=jnp.float32)  # (B,KH,g,S)
+    k, v = one_layer(k_cache), one_layer(v_cache)
+    qg = q.astype(jnp.float32).reshape(B, KH, g, D) * scale
+    logits = jnp.einsum("bkgd,bskd->bkgs", qg, k)
     k_pos = jnp.arange(S)[None, None, None, :]
     mask = k_pos < lengths[:, None, None, None]
     if window and window > 0:
         mask &= k_pos > (lengths[:, None, None, None] - 1 - window)
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
-    probs = probs / (probs.sum(axis=-1, keepdims=True) + 1e-30)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v_cache.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
+    probs = jnp.where(mask, jnp.exp(logits - logits.max(-1, keepdims=True)),
+                      0.0)
+    probs = probs / (probs.sum(-1, keepdims=True) + 1e-30)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs, v)
     return out.reshape(B, H, D).astype(q.dtype)

@@ -97,9 +97,11 @@ def attn_prefill(p, cfg, x, positions=None, mrope_pos=None):
         return out @ p["wo"], (k, v)
 
 
-def attn_decode(p, cfg, x, cache_k, cache_v, lengths, mrope_pos=None):
-    """One-token decode. x: (B,d). cache_k/v: (B,Smax,KH,hd); lengths (B,)
-    = #valid tokens BEFORE this one. Returns (out (B,d), new_k, new_v)."""
+def attn_decode(p, cfg, x, cache_k, cache_v, layer, lengths, mrope_pos=None):
+    """One-token decode. x: (B,d). cache_k/v: the layer-stacked caches
+    (L,B,Smax,KH*hd), of which ``layer`` is this layer's; lengths (B,) =
+    #valid tokens BEFORE this one. Writes the token's K/V row in place at
+    (layer, b, lengths[b]); returns (out (B,d), cache_k, cache_v)."""
     B, d = x.shape
     dp = batch_axes()
     with jax.named_scope("attn_qkv"):
@@ -110,14 +112,14 @@ def attn_decode(p, cfg, x, cache_k, cache_v, lengths, mrope_pos=None):
         else:
             q, k = _rope_qk(cfg, q, k, pos)
     with jax.named_scope("kv_write"):            # K/V at position `lengths`
-        idx = lengths[:, None, None, None]
-        S = cache_k.shape[1]
-        onehot = (jnp.arange(S)[None, :, None, None] == idx)
-        cache_k = jnp.where(onehot, k, cache_k)
-        cache_v = jnp.where(onehot, v, cache_v)
+        rows = jnp.arange(B)
+        cache_k = cache_k.at[layer, rows, lengths].set(
+            k.reshape(B, -1).astype(cache_k.dtype), mode="drop")
+        cache_v = cache_v.at[layer, rows, lengths].set(
+            v.reshape(B, -1).astype(cache_v.dtype), mode="drop")
     with jax.named_scope("attention"):
         out = da_ops.decode_attention(q[:, 0], cache_k, cache_v, lengths + 1,
-                                      window=cfg.sliding_window)
+                                      layer, window=cfg.sliding_window)
     with jax.named_scope("attn_out"):
         out = constrain(out, dp, "model", None)
         return out.reshape(B, -1) @ p["wo"], cache_k, cache_v

@@ -159,11 +159,11 @@ def shared_prefill(p, cfg, h, emb0, positions):
     return h, kv
 
 
-def shared_decode(p, cfg, h, emb0, ck, cv, lengths):
+def shared_decode(p, cfg, h, emb0, ck, cv, ins, lengths):
     x = jnp.concatenate([h, emb0], axis=-1)
     a, ck, cv = attn.attn_decode(p["attn"], cfg,
                                  cm.rmsnorm(x, p["ln"], cfg.norm_eps),
-                                 ck, cv, lengths)
+                                 ck, cv, ins, lengths)
     h = h + a
     h = h + mlp_mod.mlp_forward(p["mlp"], cfg,
                                 cm.rmsnorm(h, p["ln2"], cfg.norm_eps))
@@ -227,8 +227,9 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
     cache = {
         "ssm": jnp.zeros((L, batch_size, H, Pd, N), jnp.float32),
         "conv": jnp.zeros((L, batch_size, cfg.ssm_conv - 1, cd), dtype),
-        "k": jnp.zeros((ni, batch_size, max_len, KH, hd), dtype),
-        "v": jnp.zeros((ni, batch_size, max_len, KH, hd), dtype),
+        # one per insertion, in decode_attention's (.., S, KH*hd) layout
+        "k": jnp.zeros((ni, batch_size, max_len, KH * hd), dtype),
+        "v": jnp.zeros((ni, batch_size, max_len, KH * hd), dtype),
         "len": jnp.zeros((batch_size,), jnp.int32),
     }
     specs = {
@@ -236,8 +237,8 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
         "conv": P(None, dp, None, "model"),
         # long-context: shared-attn KV is sequence-sharded over "data"
         # when batch < data axis (DESIGN.md §5)
-        "k": P(None, dp, None, "model", None),
-        "v": P(None, dp, None, "model", None),
+        "k": P(None, dp, None, "model"),
+        "v": P(None, dp, None, "model"),
         "len": P(dp),
     }
     return cache, specs
@@ -254,6 +255,7 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
         h2, (state, conv_tail) = mamba_forward(lp, cfg, h, return_state=True)
         return h2, (state, conv_tail)
 
+    kv_width = cfg.n_kv_heads * cfg.resolved_head_dim
     states, convs, ks, vs = [], [], [], []
     for lo, hi, has_attn in _groups(cfg):
         h, (st, cv_) = jax.lax.scan(body, h,
@@ -262,8 +264,8 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
         convs.append(cv_)
         if has_attn:
             h, kv = shared_prefill(params["shared"], cfg, h, emb0, positions)
-            ks.append(kv[0])
-            vs.append(kv[1])
+            ks.append(kv[0].reshape(B, S, -1))
+            vs.append(kv[1].reshape(B, S, -1))
     hl = h[:, -1] if last_pos is None else \
         jnp.take_along_axis(h, last_pos[:, None, None].astype(jnp.int32)
                             .repeat(h.shape[-1], -1), axis=1)[:, 0]
@@ -272,10 +274,8 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
     cache = {
         "ssm": jnp.concatenate(states, 0),
         "conv": jnp.concatenate(convs, 0),
-        "k": jnp.stack(ks, 0) if ks else jnp.zeros((0, B, S, cfg.n_kv_heads,
-                                                    cfg.resolved_head_dim)),
-        "v": jnp.stack(vs, 0) if vs else jnp.zeros((0, B, S, cfg.n_kv_heads,
-                                                    cfg.resolved_head_dim)),
+        "k": jnp.stack(ks, 0) if ks else jnp.zeros((0, B, S, kv_width)),
+        "v": jnp.stack(vs, 0) if vs else jnp.zeros((0, B, S, kv_width)),
         "len": jnp.full((B,), S, jnp.int32),
     }
     return logits, cache
@@ -292,7 +292,8 @@ def decode_step(params, cfg, cache, tokens):
         h2, st, cb = mamba_decode(lp, cfg, h, st, cb)
         return h2, (st, cb)
 
-    new_ssm, new_conv, new_k, new_v = [], [], [], []
+    new_ssm, new_conv = [], []
+    ks, vs = cache["k"], cache["v"]
     ins = 0
     for lo, hi, has_attn in _groups(cfg):
         xs = (_slice_layers(params["mamba"], lo, hi),
@@ -301,19 +302,16 @@ def decode_step(params, cfg, cache, tokens):
         new_ssm.append(st)
         new_conv.append(cb)
         if has_attn:
-            h, ck, cv = shared_decode(params["shared"], cfg, h, emb0,
-                                      cache["k"][ins], cache["v"][ins],
-                                      lengths)
-            new_k.append(ck)
-            new_v.append(cv)
+            h, ks, vs = shared_decode(params["shared"], cfg, h, emb0,
+                                      ks, vs, ins, lengths)
             ins += 1
     h = cm.rmsnorm(h, params["ln_f"], cfg.norm_eps)
     logits = cm.unembed(params["emb"], cfg, h)
     new_cache = {
         "ssm": jnp.concatenate(new_ssm, 0),
         "conv": jnp.concatenate(new_conv, 0),
-        "k": jnp.stack(new_k, 0) if new_k else cache["k"],
-        "v": jnp.stack(new_v, 0) if new_v else cache["v"],
+        "k": ks,
+        "v": vs,
         "len": lengths + 1,
     }
     return logits, new_cache

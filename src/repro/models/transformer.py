@@ -2,8 +2,9 @@
 
 Layers are stacked along a leading axis and executed with ``lax.scan``
 (O(1) HLO in depth — critical for 40-cell x 512-device dry-run compile
-times) with optional remat. Decode carries per-layer KV caches through
-the same scan.
+times) with optional remat. Decode carries the layer-stacked KV cache
+and the layer index through the same scan, and each layer writes and
+reads its own part of it in place.
 """
 from __future__ import annotations
 
@@ -49,8 +50,9 @@ def layer_forward(p, cfg, h, positions, mrope_pos=None):
 # The serving programs name their parts with ``jax.named_scope`` (in the
 # op_name metadata of every HLO op; no op changes): ``embed``, ``layers``
 # (the scan) and ``unembed``; inside a layer ``attn_qkv`` (norm,
-# projections, rope), ``kv_write`` (decode's cache write), ``attention``
-# (the kernel call and its wrapper), ``attn_out`` and ``mlp``.
+# projections, rope), ``kv_write`` (decode's write of one cache row per
+# sequence), ``attention`` (the kernel call and its wrapper), ``attn_out``
+# and ``mlp``.
 def layer_prefill(p, cfg, h, positions, mrope_pos=None):
     with jax.named_scope("attn_qkv"):
         xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
@@ -66,10 +68,10 @@ def layer_prefill(p, cfg, h, positions, mrope_pos=None):
         return h + y, kv
 
 
-def layer_decode(p, cfg, h, ck, cv, lengths, mrope_pos=None):
+def layer_decode(p, cfg, h, ck, cv, layer, lengths, mrope_pos=None):
     with jax.named_scope("attn_qkv"):
         xn = cm.rmsnorm(h, p["ln1"], cfg.norm_eps)
-    a, ck, cv = attn.attn_decode(p["attn"], cfg, xn, ck, cv, lengths,
+    a, ck, cv = attn.attn_decode(p["attn"], cfg, xn, ck, cv, layer, lengths,
                                  mrope_pos)
     with jax.named_scope("attn_out"):
         h = h + a
@@ -142,16 +144,18 @@ def forward(params, cfg, batch: Dict):
 
 # ------------------------------------------------------------------ serving
 def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
+    """K/V are (L, B, max_len, KH*hd): token major, the kv heads folded
+    into the minor dim, the layout decode_attention reads in place."""
     L, KH, hd = cfg.n_layers, cfg.n_kv_heads, cfg.resolved_head_dim
     dp = ("data",)
     # kv_seq_shard: shard the sequence dim over "model" when kv heads
     # cannot use it (GQA kv < TP) — attention reductions over the sharded
     # seq become scalar psums (EXPERIMENTS §Perf C3)
-    kv_spec = P(None, dp, "model", None, None) if cfg.kv_seq_shard \
-        else P(None, dp, None, "model", None)
+    kv_spec = P(None, dp, "model", None) if cfg.kv_seq_shard \
+        else P(None, dp, None, "model")
     cache = {
-        "k": jnp.zeros((L, batch_size, max_len, KH, hd), dtype),
-        "v": jnp.zeros((L, batch_size, max_len, KH, hd), dtype),
+        "k": jnp.zeros((L, batch_size, max_len, KH * hd), dtype),
+        "v": jnp.zeros((L, batch_size, max_len, KH * hd), dtype),
         "len": jnp.zeros((batch_size,), jnp.int32),
     }
     specs = {"k": kv_spec, "v": kv_spec, "len": P(dp)}
@@ -178,9 +182,10 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
                                 .repeat(h.shape[-1], -1), axis=1)[:, 0]
         hl = cm.rmsnorm(hl, params["ln_f"], cfg.norm_eps)
         logits = cm.unembed(params["emb"], cfg, hl)
-    S_tot = ks.shape[2]
-    cache = {"k": ks, "v": vs,
-             "len": jnp.full((h.shape[0],), S_tot, jnp.int32)}
+    L, B, S_tot = ks.shape[:3]                  # (L, B, S, KH, hd)
+    cache = {"k": ks.reshape(L, B, S_tot, -1),
+             "v": vs.reshape(L, B, S_tot, -1),
+             "len": jnp.full((B,), S_tot, jnp.int32)}
     return logits, cache
 
 
@@ -195,14 +200,15 @@ def decode_step(params, cfg, cache, tokens):
         pos = lengths[None, :, None]                        # (1,B,1)
         mrope_pos = jnp.broadcast_to(pos, (3, B, 1))
 
-    def body(h, xs):
-        lp, ck, cv = xs
-        h2, ck, cv = layer_decode(lp, cfg, h, ck, cv, lengths, mrope_pos)
-        return h2, (ck, cv)
+    def body(carry, lp):
+        h, ks, vs, layer = carry
+        h, ks, vs = layer_decode(lp, cfg, h, ks, vs, layer, lengths,
+                                 mrope_pos)
+        return (h, ks, vs, layer + 1), None
 
     with jax.named_scope("layers"):
-        h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], cache["k"],
-                                             cache["v"]))
+        (h, ks, vs, _), _ = jax.lax.scan(
+            body, (h, cache["k"], cache["v"], jnp.int32(0)), params["layers"])
     with jax.named_scope("unembed"):
         h = cm.rmsnorm(h, params["ln_f"], cfg.norm_eps)
         logits = cm.unembed(params["emb"], cfg, h)

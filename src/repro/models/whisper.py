@@ -4,7 +4,8 @@ The conv audio frontend is a STUB per the assignment: the model consumes
 precomputed frame embeddings (B, enc_seq, d_model). Sinusoidal positions
 on both sides (simplification noted in DESIGN.md). Decoder layers carry
 causal self-attention + cross-attention into the encoder output; decode
-caches both the self KV and the (fixed) cross KV.
+caches both the self KV and the (fixed) cross KV, layer-stacked in
+decode_attention's (L, B, S, KH*hd) layout and read by layer index.
 """
 from __future__ import annotations
 
@@ -119,18 +120,18 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
     F = cfg.enc_seq
     dp = ("data",)
     cache = {
-        "k": jnp.zeros((L, batch_size, max_len, KH, hd), dtype),
-        "v": jnp.zeros((L, batch_size, max_len, KH, hd), dtype),
-        "xk": jnp.zeros((L, batch_size, F, KH, hd), dtype),
-        "xv": jnp.zeros((L, batch_size, F, KH, hd), dtype),
+        "k": jnp.zeros((L, batch_size, max_len, KH * hd), dtype),
+        "v": jnp.zeros((L, batch_size, max_len, KH * hd), dtype),
+        "xk": jnp.zeros((L, batch_size, F, KH * hd), dtype),
+        "xv": jnp.zeros((L, batch_size, F, KH * hd), dtype),
         "len": jnp.zeros((batch_size,), jnp.int32),
     }
-    kv_spec = P(None, dp, "model", None, None) if cfg.kv_seq_shard \
-        else P(None, dp, None, "model", None)
+    kv_spec = P(None, dp, "model", None) if cfg.kv_seq_shard \
+        else P(None, dp, None, "model")
     specs = {"k": kv_spec,
              "v": kv_spec,
-             "xk": P(None, dp, None, "model", None),
-             "xv": P(None, dp, None, "model", None),
+             "xk": P(None, dp, None, "model"),
+             "xv": P(None, dp, None, "model"),
              "len": P(dp)}
     return cache, specs
 
@@ -162,8 +163,10 @@ def prefill(params, cfg, batch: Dict, last_pos=None):
                             .repeat(h.shape[-1], -1), axis=1)[:, 0]
     hl = cm.rmsnorm(hl, params["ln_f"], cfg.norm_eps)
     logits = cm.unembed(params["emb"], cfg, hl)
-    cache = {"k": ks, "v": vs, "xk": xks, "xv": xvs,
-             "len": jnp.full((B,), S, jnp.int32)}
+    L = ks.shape[0]                             # each (L, B, S, KH, hd)
+    cache = {name: c.reshape(L, B, c.shape[2], -1) for name, c in
+             (("k", ks), ("v", vs), ("xk", xks), ("xv", xvs))}
+    cache["len"] = jnp.full((B,), S, jnp.int32)
     return logits, cache
 
 
@@ -181,24 +184,23 @@ def decode_step(params, cfg, cache, tokens):
     F = cfg.enc_seq
     flen = jnp.full((B,), F, jnp.int32)
 
-    def body(h, xs):
-        lp, ck, cv, xk, xv = xs
+    def body(carry, lp):
+        h, ks, vs, layer = carry
         xn = cm.rmsnorm(h, lp["ln1"], cfg.norm_eps)
-        a, ck, cv = attn.attn_decode(lp["attn"], cfg, xn, ck, cv, lengths)
+        a, ks, vs = attn.attn_decode(lp["attn"], cfg, xn, ks, vs, layer,
+                                     lengths)
         h = h + a
         x = cm.rmsnorm(h, lp["ln_x"], cfg.norm_eps)
-        KH, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         q = (x @ lp["xattn"]["wq"] + lp["xattn"].get("bq", 0)).reshape(
-            B, cfg.n_heads, hd)
-        cx = da_ops.decode_attention(q, xk, xv, flen)
+            B, cfg.n_heads, cfg.resolved_head_dim)
+        cx = da_ops.decode_attention(q, cache["xk"], cache["xv"], flen, layer)
         h = h + cx.reshape(B, -1) @ lp["xattn"]["wo"]
         h = h + mlp_mod.mlp_forward(lp["mlp"], cfg,
                                     cm.rmsnorm(h, lp["ln2"], cfg.norm_eps))
-        return h, (ck, cv)
+        return (h, ks, vs, layer + 1), None
 
-    h, (ks, vs) = jax.lax.scan(
-        body, h, (params["dec_layers"], cache["k"], cache["v"],
-                  cache["xk"], cache["xv"]))
+    (h, ks, vs, _), _ = jax.lax.scan(
+        body, (h, cache["k"], cache["v"], jnp.int32(0)), params["dec_layers"])
     h = cm.rmsnorm(h, params["ln_f"], cfg.norm_eps)
     logits = cm.unembed(params["emb"], cfg, h)
     new_cache = dict(cache, k=ks, v=vs, len=lengths + 1)

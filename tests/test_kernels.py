@@ -1,5 +1,7 @@
 """Per-kernel correctness sweeps: Pallas (interpret mode) vs ref oracle
 across shapes and dtypes, plus gradient checks through the custom vjps."""
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,7 @@ import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
+from repro.kernels.decode_attention import kernel as da_kernel
 from repro.kernels.decode_attention import ops as da_ops
 from repro.kernels.mamba_scan import ops as ms_ops
 from repro.kernels.mamba_scan import ref as ms_ref
@@ -68,23 +71,37 @@ def test_chunked_mha_matches_full():
 
 
 # ----------------------------------------------------------------- decode
+# the layer-stacked cache (L, B, S, KH*D) read at its first and last layer;
+# hd 64 with G = 1 is minicpm-2b's MHA, hd 128 with G = 6 qwen2-1.5b's GQA
 @pytest.mark.parametrize("B,H,KH,D,S", [
     (2, 4, 2, 64, 512), (1, 8, 1, 128, 256), (3, 6, 6, 32, 512),
+    (4, 4, 4, 64, 512), (4, 12, 2, 128, 256),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_sweep(B, H, KH, D, S, dtype):
+    L = 3
     rng = np.random.default_rng(hash((B, H, KH, D, S)) % 2**31)
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
-    kc = jnp.asarray(rng.normal(size=(B, S, KH, D)), dtype)
-    vc = jnp.asarray(rng.normal(size=(B, S, KH, D)), dtype)
-    lens = jnp.asarray(rng.integers(1, S + 1, size=(B,)), jnp.int32)
-    for window in (0, 64):
-        ref = da_ops.decode_attention(q, kc, vc, lens, window=window,
+    kc = jnp.asarray(rng.normal(size=(L, B, S, KH * D)), dtype)
+    vc = jnp.asarray(rng.normal(size=(L, B, S, KH * D)), dtype)
+    drawn = rng.integers(1, S + 1, size=(B,))
+    edges = np.resize([0, 1, int(rng.integers(2, S)), S], B)  # empty..full
+    for lens, layer, window in itertools.product(
+            (drawn, edges), (0, L - 1), (0, 64)):
+        lens = jnp.asarray(lens, jnp.int32)
+        ref = da_ops.decode_attention(q, kc, vc, lens, layer, window=window,
                                       impl="ref")
-        out = da_ops.decode_attention(q, kc, vc, lens, window=window,
-                                      impl="pallas_interpret")
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(ref, np.float32), **_tol(dtype))
+        outs = [da_ops.decode_attention(q, kc, vc, lens, layer,
+                                        window=window,
+                                        impl="pallas_interpret"),
+                # several blocks a row, so dead ones are skipped
+                da_kernel.decode_attention_pallas(q, kc, vc, lens, layer,
+                                                  window=window, bk=128,
+                                                  interpret=True)]
+        for out in outs:
+            np.testing.assert_allclose(np.asarray(out, np.float32),
+                                       np.asarray(ref, np.float32),
+                                       **_tol(dtype))
 
 
 # -------------------------------------------------------------------- ssd

@@ -63,8 +63,8 @@ def test_smoke_decode_matches_forward(arch):
     batch = _batch_for(cfg, B, S)
     lp, cache = model.prefill(params, cfg, batch)
     nxt = jnp.argmax(lp[:, :cfg.vocab_size], -1)
-    if "k" in cache and cache["k"].ndim == 5:
-        pad = ((0, 0), (0, 0), (0, 4), (0, 0), (0, 0))
+    if "k" in cache:                    # (L, B, S, KH*hd): room to decode
+        pad = ((0, 0), (0, 0), (0, 4), (0, 0))
         cache = dict(cache, k=jnp.pad(cache["k"], pad),
                      v=jnp.pad(cache["v"], pad))
     ld, cache = model.decode_step(params, cfg, cache, nxt)

@@ -21,7 +21,7 @@ def setup():
 def _direct_greedy(model, cfg, params, prompt, n_new):
     batch = {"tokens": jnp.asarray(prompt)[None, :]}
     logits, cache = model.prefill(params, cfg, batch)
-    pad = ((0, 0), (0, 0), (0, n_new + 1), (0, 0), (0, 0))
+    pad = ((0, 0), (0, 0), (0, n_new + 1), (0, 0))     # (L, B, S, KH*hd)
     cache = dict(cache, k=jnp.pad(cache["k"], pad),
                  v=jnp.pad(cache["v"], pad))
     toks = [int(jnp.argmax(logits[0, :cfg.vocab_size]))]
@@ -49,6 +49,26 @@ def test_engine_matches_direct_greedy(setup):
         want = _direct_greedy(model, cfg, params, p, n_new)
         got = finished[i].out_tokens
         assert got == want, (i, got, want)
+
+
+def test_engine_matches_direct_greedy_mha_hd64():
+    """minicpm-2b's cache shape at a tiny size: MHA with head dim 64, so
+    the folded (.., S, KH*hd) rows hold whole 64-lane heads."""
+    cfg = get_smoke_config("minicpm-2b").with_(d_model=128, n_heads=2,
+                                                n_kv_heads=2)
+    assert cfg.resolved_head_dim == 64
+    model = mapi.get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(1), cfg)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, cfg.vocab_size, size=(int(n),))
+               for n in (3, 12, 17)]
+    eng = JaxEngine(cfg, params, max_batch=2, max_len=64)
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, 5)
+    finished = eng.drain()
+    for i, p in enumerate(prompts):
+        assert finished[i].out_tokens == \
+            _direct_greedy(model, cfg, params, p, 5), i
 
 
 def test_engine_slot_reuse(setup):
