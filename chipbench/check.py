@@ -52,23 +52,23 @@ def sequence_of(prompt, out_tokens) -> Compared:
     return Compared(tokens, sel, served)
 
 
-def gaps(params, conf: dict, seqs: List[Compared],
+def gaps(family, params, conf: dict, seqs: List[Compared],
          controls: Sequence[str] = ()) -> dict:
-    """Widest gap of the served tokens (``max_logit_gap``), the number
-    of positions compared and the median gap between the reference's
-    two best logits there (``top2_gap_median``: how near the ties are),
-    how many served tokens repeat the token before them (``repeats``);
-    for each control mode in ``controls`` (``"fp8"``) also the widest gap of
-    the tokens that the control puts first at the same positions
-    (``control_gap.<mode>``)."""
+    """Against ``family``'s reference: the widest gap of the served
+    tokens (``max_logit_gap``), the number of positions compared and the
+    median gap between the reference's two best logits there
+    (``top2_gap_median``: how near the ties are), how many served tokens
+    repeat the token before them (``repeats``); for each control mode in
+    ``controls`` (``"fp8"``) also the widest gap of the tokens that the
+    control puts first at the same positions (``control_gap.<mode>``)."""
     out = {"max_logit_gap": 0.0, "positions": 0}
     out.update({f"control_gap.{m}": 0.0 for m in controls})
     top2 = []
     C = reference.CHUNK
     for s in seqs:
-        refs = reference.logits_at(params, conf, s.tokens, s.sel)
-        ctrl = {m: reference.logits_at(params, conf, s.tokens, s.sel, m)
-                for m in controls}
+        refs = reference.logits_at(family, params, conf, s.tokens, s.sel)
+        ctrl = {m: reference.logits_at(family, params, conf, s.tokens,
+                                       s.sel, m) for m in controls}
         for i, ref in enumerate(refs):
             served = np.zeros(C, np.int32)
             part = s.served[i * C:(i + 1) * C]

@@ -5,33 +5,19 @@ active slot, the real prompt length), never from the shapes that the
 implementation happens to pad to or stream. A multiply-add is 2
 operations. Softmax, norms and RoPE are left out: they are a small
 share of the work and of no matrix unit.
+
+What depends on the model family (its linear layers, which layers
+attend, what else a step computes) comes from the family's module
+(``chipbench/families/<family>.py``); attention, the logits and the
+roofline are shared here.
 """
 from __future__ import annotations
 
 from typing import Iterable, Tuple
 
-from chipbench.reference import head_dim
-
-
-def _dims(conf: dict):
-    return (conf["num_hidden_layers"], conf["hidden_size"],
-            conf["num_attention_heads"], conf["num_key_value_heads"],
-            head_dim(conf), conf["intermediate_size"], conf["vocab_size"])
-
 
 def elem_bytes(conf: dict) -> int:
     return {"bfloat16": 2, "float16": 2, "float32": 4}[conf["torch_dtype"]]
-
-
-def layer_matmul_params(conf: dict) -> int:
-    """Weights of one layer's matrix products (q, k, v, o and SwiGLU)."""
-    _, d, H, KH, hd, f, _ = _dims(conf)
-    return d * (H + 2 * KH) * hd + H * hd * d + 3 * d * f
-
-
-def token_matmul_flops(conf: dict) -> int:
-    """Operations of the linear layers for one token through every layer."""
-    return 2 * conf["num_hidden_layers"] * layer_matmul_params(conf)
 
 
 def unembed_flops(conf: dict) -> int:
@@ -39,11 +25,14 @@ def unembed_flops(conf: dict) -> int:
     return 2 * conf["hidden_size"] * conf["vocab_size"]
 
 
-def decode_attention_cost(conf: dict, live: Iterable[int]) -> Tuple[int, int]:
-    """(operations, bytes) of single-token attention over every layer, for
-    slots that attend over ``live`` keys each: q.K and p.V over the live
-    keys; the live K and V read once, q read and the output written."""
-    L, _, H, KH, hd, _, _ = _dims(conf)
+def decode_attention_cost(dims, conf: dict,
+                          live: Iterable[int]) -> Tuple[int, int]:
+    """(operations, bytes) of single-token attention over every layer
+    that attends (``dims``: layers, query heads, key/value heads, head
+    size; a family's ``attention_dims``), for slots that attend over
+    ``live`` keys each: q.K and p.V over the live keys; the live K and V
+    read once, q read and the output written."""
+    L, H, KH, hd = dims
     b = elem_bytes(conf)
     flops = nbytes = 0
     for n in live:
@@ -52,28 +41,23 @@ def decode_attention_cost(conf: dict, live: Iterable[int]) -> Tuple[int, int]:
     return flops, nbytes
 
 
-def decode_step_flops(conf: dict, live: Iterable[int]) -> int:
-    """One decode step: linear layers, attention and logits per active slot."""
-    live = list(live)
-    per_slot = token_matmul_flops(conf) + unembed_flops(conf)
-    return len(live) * per_slot + decode_attention_cost(conf, live)[0]
-
-
-def flash_attention_cost(conf: dict, s: int) -> Tuple[int, int]:
+def flash_attention_cost(dims, conf: dict, s: int) -> Tuple[int, int]:
     """(operations, bytes) of causal attention over a prompt of ``s`` real
-    tokens, every layer: s(s+1)/2 query-key pairs, each 2*hd for q.k and
-    2*hd for p.v, per head; q, k, v read and the output written once."""
-    L, _, H, KH, hd, _, _ = _dims(conf)
+    tokens, every layer that attends (``dims`` as above): s(s+1)/2
+    query-key pairs, each 2*hd for q.k and 2*hd for p.v, per head; q, k,
+    v read and the output written once."""
+    L, H, KH, hd = dims
     flops = L * 2 * H * hd * s * (s + 1)
     nbytes = L * elem_bytes(conf) * s * hd * (2 * H + 2 * KH)
     return flops, nbytes
 
 
-def prefill_flops(conf: dict, s: int) -> int:
-    """A prompt of ``s`` real tokens: linear layers for every token,
-    causal attention, and logits at the last position only."""
-    return s * token_matmul_flops(conf) + flash_attention_cost(conf, s)[0] \
-        + unembed_flops(conf)
+def decode_step_flops(family, conf: dict, live: Iterable[int]) -> int:
+    """One decode step: linear layers and logits per active slot, and
+    what the family adds (attention over the live keys)."""
+    live = list(live)
+    per_slot = family.linear_flops(conf) + unembed_flops(conf)
+    return len(live) * per_slot + family.decode_flops(conf, live)
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> float:

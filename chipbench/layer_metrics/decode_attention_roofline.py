@@ -16,7 +16,8 @@ def read(ctx):
     secs, calls = devtrace.kernel_seconds(ctx.events, NAMES)
     if not steps or not calls or secs <= 0:
         return None
+    dims = ctx.cell.family.attention_dims(ctx.conf)
     need = sum(counts.roofline_seconds(
-        *counts.decode_attention_cost(ctx.conf, s.live), ctx.peaks)
+        *counts.decode_attention_cost(dims, ctx.conf, s.live), ctx.peaks)
         for s in steps)
     return 100.0 * need / secs

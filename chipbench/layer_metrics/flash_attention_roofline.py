@@ -16,7 +16,8 @@ def read(ctx):
     secs, calls = devtrace.kernel_seconds(ctx.events, NAMES)
     if not pre or not calls or secs <= 0:
         return None
+    dims = ctx.cell.family.attention_dims(ctx.conf)
     need = sum(counts.roofline_seconds(
-        *counts.flash_attention_cost(ctx.conf, len(p.req.prompt)), ctx.peaks)
-        for p in pre)
+        *counts.flash_attention_cost(dims, ctx.conf, len(p.req.prompt)),
+        ctx.peaks) for p in pre)
     return 100.0 * need / secs

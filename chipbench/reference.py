@@ -1,24 +1,19 @@
-"""Plain float32 reference of a dense decoder, and its lower-precision control.
+"""The plain float32 reference's shared parts, and the gap arithmetic.
 
-Straight ``jax.numpy`` at ``Precision.HIGHEST``: no kernels, no cache,
-no batching. It follows the published description of the configuration
-file (RMSNorm, RoPE with rotate-half, grouped-query attention with
-optional q/k/v bias, SwiGLU, tied or untied unembedding, MiniCPM's muP
-scalars), reads the benchmark's weights by their names in the served
-tree, and imports nothing of the program. Layers run one at a time under
-``lax.scan``, each cast to float32 as it is used, so that the whole
-model never sits on the device in float32.
+Each model family's reference (``chipbench/families/<family>.py``,
+``reference(conf, mode)``) is straight ``jax.numpy`` at
+``Precision.HIGHEST`` built from the pieces here: no kernels, no cache,
+no batching, and nothing of the program. ``logits_at`` runs it over one
+sequence; ``chunk_readings`` reads the gaps from its logits.
 
-``mode="fp8"`` makes it the control, computed one precision below the
-bf16 that the configurations state: every matrix product of the linear
-layers takes operands rounded to fp8 E4M3 (scaled per row of
+``mode="fp8"`` makes a reference the control, computed one precision
+below the bf16 that the configurations state: every matrix product of
+the linear layers takes operands rounded to fp8 E4M3 (scaled per row of
 activations and per output column of weights) and accumulates in
 float32.
 """
 from __future__ import annotations
 
-import functools
-import math
 from typing import Optional
 
 import jax
@@ -29,23 +24,7 @@ HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 
 
-def head_dim(conf: dict) -> int:
-    return conf.get("head_dim") or conf["hidden_size"] // conf["num_attention_heads"]
-
-
-def mup_factors(conf: dict):
-    """MiniCPM's muP scalars (arXiv:2404.06395): the embedding factor,
-    the factor on each residual branch and the divisor of the logits;
-    1, 1, 1 for a model that has none."""
-    emb = float(conf.get("scale_emb") or 1.0)
-    depth = conf.get("scale_depth")
-    branch = depth / math.sqrt(conf["num_hidden_layers"]) if depth else 1.0
-    base = conf.get("dim_model_base")
-    logits = conf["hidden_size"] / base if base else 1.0
-    return emb, branch, logits
-
-
-def _quantize(x, axis: int, mode: Optional[str]):
+def quantize(x, axis: int, mode: Optional[str]):
     if mode is None:
         return x
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
@@ -55,17 +34,17 @@ def _quantize(x, axis: int, mode: Optional[str]):
     raise ValueError(f"unknown mode {mode!r}")
 
 
-def _linear(x, w, mode):
+def linear(x, w, mode):
     """x (T, din) @ w (din, dout), operands rounded per ``mode``."""
-    return jnp.matmul(_quantize(x, -1, mode), _quantize(w, 0, mode),
+    return jnp.matmul(quantize(x, -1, mode), quantize(w, 0, mode),
                       precision=HI)
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (T, heads, hd): rotate-half RoPE at integer positions ``pos``."""
     half = x.shape[-1] // 2
     inv = 1.0 / (theta ** (jnp.arange(half, dtype=F32) / half))
@@ -73,65 +52,6 @@ def _rope(x, pos, theta):
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _conf_key(conf: dict):
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "intermediate_size", "vocab_size",
-            "rope_theta", "rms_norm_eps", "tie_word_embeddings",
-            "scale_emb", "scale_depth", "dim_model_base", "head_dim")
-    return tuple((k, conf.get(k)) for k in keys)
-
-
-@functools.lru_cache(maxsize=None)
-def _hidden_fn(conf_key, mode):
-    conf = dict(conf_key)
-    H, KH = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd, V = head_dim(conf), conf["vocab_size"]
-    eps, theta = conf["rms_norm_eps"], conf["rope_theta"]
-    emb_f, branch_f, logit_f = mup_factors(conf)
-
-    def hidden(params, tokens):
-        """tokens (T,) -> the final norm's output (T, d), float32."""
-        T = tokens.shape[0]
-        E = params["emb"]["embed"][:V].astype(F32) / emb_f     # published E
-        h = emb_f * E[tokens]
-        pos = jnp.arange(T)
-        causal = pos[None, :] <= pos[:, None]
-
-        def layer(h, lp):
-            lp = jax.tree.map(lambda a: a.astype(F32), lp)
-            at, ml = lp["attn"], lp["mlp"]
-            x = _rmsnorm(h, lp["ln1"]["scale"], eps)
-            q, k, v = (_linear(x, at[w], mode) + at.get(b, 0.0)
-                       for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
-            q = _rope(q.reshape(T, H, hd), pos, theta)
-            k = _rope(k.reshape(T, KH, hd), pos, theta)
-            v = v.reshape(T, KH, hd)
-            k, v = jnp.repeat(k, H // KH, 1), jnp.repeat(v, H // KH, 1)
-            s = jnp.einsum("thd,shd->hts", q, k, precision=HI) * hd ** -0.5
-            p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), -1)
-            o = jnp.einsum("hts,shd->thd", p, v, precision=HI)
-            h = h + branch_f * _linear(o.reshape(T, H * hd),
-                                       at["wo"] / branch_f, mode)
-            x = _rmsnorm(h, lp["ln2"]["scale"], eps)
-            g = jax.nn.silu(_linear(x, ml["wg"], mode)) * _linear(x, ml["wu"], mode)
-            h = h + branch_f * _linear(g, ml["wd"] / branch_f, mode)
-            return h, None
-
-        h, _ = jax.lax.scan(layer, h, params["layers"])
-        scale = params["ln_f"]["scale"].astype(F32) * emb_f * logit_f
-        return _rmsnorm(h, scale, eps)
-
-    def logits(params, hn, sel):
-        """Final norm's output (T, d) at rows ``sel`` (P,) -> float32
-        logits (P, V)."""
-        E = params["emb"]["embed"][:V].astype(F32) / emb_f
-        W = E.T if "unembed" not in params["emb"] else \
-            params["emb"]["unembed"][:, :V].astype(F32)
-        return _linear(hn[sel], W, mode) / logit_f
-
-    return jax.jit(hidden), jax.jit(logits)
 
 
 def _bucket(n: int, least: int, most: int) -> int:
@@ -144,15 +64,16 @@ def _bucket(n: int, least: int, most: int) -> int:
 CHUNK = 256     # positions whose logits are formed at once
 
 
-def logits_at(params, conf: dict, tokens: np.ndarray, sel: np.ndarray,
-              mode: Optional[str] = None):
-    """Reference (or control) logits of the sequence ``tokens`` at
-    positions ``sel``, as a list of float32 arrays of ``CHUNK`` rows (the
-    last padded with rows of position 0). The sequence is padded at its
-    end to a power of two from 256 up, at most the engine's ``max_len``
-    (causal attention leaves earlier positions unchanged), and logits are
-    formed ``CHUNK`` positions at a time, so that few shapes compile."""
-    hidden, logits = _hidden_fn(_conf_key(conf), mode)
+def logits_at(family, params, conf: dict, tokens: np.ndarray,
+              sel: np.ndarray, mode: Optional[str] = None):
+    """``family``'s reference (or control) logits of the sequence
+    ``tokens`` at positions ``sel``, as a list of float32 arrays of
+    ``CHUNK`` rows (the last padded with rows of position 0). The
+    sequence is padded at its end to a power of two from 256 up, at most
+    the engine's ``max_len`` (causal attention leaves earlier positions
+    unchanged), and logits are formed ``CHUNK`` positions at a time, so
+    that few shapes compile."""
+    hidden, logits = family.reference(conf, mode)
     T = len(tokens)
     tok = np.zeros(_bucket(T, 256, conf["engine"]["max_len"]), np.int32)
     tok[:T] = tokens

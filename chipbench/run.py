@@ -101,9 +101,9 @@ def check_devices(cell) -> dict:
 def build(cell, seed: int):
     """The weights drawn from ``seed`` and the engine serving them."""
     from chipbench import system
-    conf, dev = cell.config, jax.devices()[0]
-    cfg = system.program_config(conf)
-    params = system.make_params(conf, cfg, seed, dev)
+    family, conf, dev = cell.family, cell.config, jax.devices()[0]
+    cfg = family.program_config(conf)
+    params = system.make_params(family, conf, cfg, seed, dev)
     return params, system.make_engine(conf, cfg, params, dev)
 
 
@@ -195,7 +195,7 @@ def compare(cell, params, run, seed: int, controls=()):
     from chipbench import check
     finished = [s for s in run.served if s.finished]
     chosen = check.sample(finished, cell.cell["check"]["requests"], seed)
-    got = check.gaps(params, cell.config,
+    got = check.gaps(cell.family, params, cell.config,
                      [check.sequence_of(s.er.prompt, s.er.out_tokens)
                       for s in chosen], controls)
     limit = cell.cell["check"]["max_logit_gap"]

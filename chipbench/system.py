@@ -1,6 +1,7 @@
-"""The system under test, as the benchmark sets it up: the program's model
-configuration built from a configuration file, the weights drawn from the
-seed, and the engine that serves them.
+"""The system under test, as the benchmark sets it up: the weights drawn
+from the seed, and the engine that serves them. What the weights are,
+and the program's model configuration they fit, belong to the model's
+family (``chipbench/families/<family>.py``).
 
 The weights are the benchmark's own: one jitted call draws every leaf on
 the device, in the dtype served, in the tree layout that the program's
@@ -10,41 +11,10 @@ program made.
 """
 from __future__ import annotations
 
-import math
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import get_config
-from repro.models import api as mapi
 from repro.serving.engine import JaxEngine
-from chipbench.reference import head_dim, mup_factors
-
-# configuration-file key -> the program's ModelConfig field
-FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings", "qkv_bias": "qkv_bias",
-    "torch_dtype": "dtype",
-}
-# spreads of the random weights (standard deviations, or around 1 for
-# norm scales); matrices are N(0, 1/fan_in) as the program's own init
-NORM_SPREAD, BIAS_SPREAD = 0.1, 0.5
-
-
-def program_config(conf: dict):
-    """The program's ModelConfig for configuration file ``conf``."""
-    cfg = get_config(conf["arch"]).with_(**{f: conf[k] for k, f in FIELDS.items()})
-    if cfg.family != "dense" or cfg.sliding_window or cfg.rope != "rope":
-        raise ValueError(f"{conf['arch']}: the benchmark's reference covers "
-                         "dense full-attention RoPE models only")
-    if cfg.resolved_head_dim != head_dim(conf):
-        raise ValueError(f"head_dim {head_dim(conf)} is not what the program "
-                         f"runs ({cfg.resolved_head_dim})")
-    return cfg
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -54,56 +24,12 @@ def seed_key(seed: int) -> jax.Array:
                                     impl="threefry2x32")
 
 
-def make_params(conf: dict, cfg, seed: int, device):
-    """Weights drawn from ``seed`` on ``device`` in one jitted call.
-
-    Matrices are N(0, 1/fan_in); the embedding's rows, as the first layer
-    sees them, have unit norm. The program has no muP scalars, so the
-    published ones are folded into the weights it serves: the published
-    (tied) embedding is drawn at 1/scale_emb of that spread, so that
-    ``scale_emb`` times it is what the program looks up; each residual
-    branch's output matrix (``wo``, ``wd``) is scaled by the branch
-    factor; and the final norm's scale is divided by ``scale_emb`` and by
-    the logit divisor. The function computed is the published one. (Drawn
-    at the full spread, the scaled embedding would outweigh the branches,
-    and through the tied unembedding every token would predict itself.)
-    """
-    model = mapi.get_model(cfg)
-    shapes = jax.eval_shape(lambda k: model.init(k, cfg)[0],
-                            jax.random.PRNGKey(0))
-    leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
-    emb_f, branch_f, logit_f = mup_factors(conf)
-    if (emb_f, logit_f) != (1.0, 1.0) and not conf["tie_word_embeddings"]:
-        raise ValueError("muP folding is written for tied embeddings")
-    V, dt = conf["vocab_size"], jnp.dtype(conf["torch_dtype"])
-
-    def draw(key):
-        out = []
-        for i, (path, sd) in enumerate(leaves):
-            name = path[-1].key
-            k = jax.random.fold_in(key, i)
-            z = jax.random.normal(k, sd.shape, jnp.float32)
-            if name == "scale":
-                w = 1.0 + NORM_SPREAD * z
-                if path[0].key == "ln_f":
-                    w = w / (emb_f * logit_f)
-            elif name in ("bq", "bk", "bv"):
-                w = BIAS_SPREAD * z
-            elif name == "embed":       # rows past the vocabulary are padding
-                w = z / math.sqrt(sd.shape[-1])
-                w = jnp.where(jnp.arange(sd.shape[0])[:, None] < V, w, 0.0)
-            elif name == "unembed":
-                w = z / math.sqrt(sd.shape[0])
-                w = jnp.where(jnp.arange(sd.shape[1])[None, :] < V, w, 0.0)
-            else:                       # (layers, fan_in, fan_out)
-                w = z / math.sqrt(sd.shape[-2])
-                if name in ("wo", "wd"):
-                    w = w * branch_f
-            out.append(w.astype(dt))
-        return jax.tree_util.tree_unflatten(tree, out)
-
+def make_params(family, conf: dict, cfg, seed: int, device):
+    """``family``'s weights (``draw_params``) for configuration ``conf``,
+    drawn from ``seed`` on ``device`` in one jitted call."""
     sharding = jax.sharding.SingleDeviceSharding(device)
-    return jax.jit(draw, out_shardings=sharding)(seed_key(seed))
+    return jax.jit(lambda key: family.draw_params(conf, cfg, key),
+                   out_shardings=sharding)(seed_key(seed))
 
 
 def make_engine(conf: dict, cfg, params, device) -> JaxEngine:

@@ -1,5 +1,6 @@
 """Tiny cells for the CPU tests: the committed cells with their models cut
 to a tiny width and their traffic to short requests."""
+import dataclasses
 import importlib.util
 import json
 import time
@@ -46,8 +47,7 @@ def tiny_cell(name: str) -> spec.Cell:
     cell = dict(c.cell, check={"requests": 6, "max_logit_gap": TINY_LIMIT[name]})
     if "rate_per_s" in cell:
         cell["rate_per_s"] = 40.0      # enough to keep every slot busy
-    return spec.Cell(c.name, c.chips, c.config_name, c.traffic_name, conf,
-                     traffic, cell, c.end_to_end, c.per_layer)
+    return dataclasses.replace(c, config=conf, traffic=traffic, cell=cell)
 
 
 CELLS = [w["name"] for w in json.loads(
